@@ -2,7 +2,8 @@
 
   table1/table2  -> bench_convergence  (cross-source MAE matrices, §5.1)
   fig4           -> bench_scaling      (weak/strong MTL-par vs MTL-base;
-                                        subprocess: needs 512 host devices)
+                                        subprocess on the CPU backend: needs
+                                        512 host devices)
   roofline       -> roofline           (per arch x shape terms from the
                                         dry-run artifact, §Roofline)
   kernels        -> bench_kernels      (attention / segment-sum layers)
@@ -38,7 +39,10 @@ def run_convergence(fast: bool):
 
 
 def run_scaling():
+    # a forced-host-device sweep: the child runs on the CPU backend (and its
+    # output says so), so it never competes with this process for a chip
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     p = subprocess.run([sys.executable, "-m", "benchmarks.bench_scaling"],

@@ -602,34 +602,6 @@ def rule_don001(mi: ModuleInfo) -> list[Finding]:
 # PAL — Pallas contracts
 # ---------------------------------------------------------------------------
 
-def rule_pal001(mi: ModuleInfo) -> list[Finding]:
-    """Bare int literals inside ``pl.load``/``pl.store`` index tuples — the
-    exact PR 3 flash_decode bug: jax 0.4.x interpret-mode discharge probes
-    ``.shape`` on every non-Slice index entry and chokes."""
-    out = []
-    for node in ast.walk(mi.tree):
-        if not isinstance(node, ast.Call):
-            continue
-        qn = mi.qualname(node.func)
-        if qn not in (f"{_PL}.load", f"{_PL}.store"):
-            continue
-        if len(node.args) < 2 or not isinstance(node.args[1], ast.Tuple):
-            continue
-        for el in node.args[1].elts:
-            bad = isinstance(el, ast.Constant) and isinstance(el.value, int)
-            bad = bad or (isinstance(el, ast.UnaryOp)
-                          and isinstance(el.operand, ast.Constant)
-                          and isinstance(el.operand.value, int))
-            if bad:
-                out.append(mi.finding(
-                    "PAL001", el,
-                    f"bare int `{ast.unparse(el)}` in a "
-                    f"`{qn.rsplit('.', 1)[-1]}` index tuple",
-                    "index unit dims with pl.dslice(i, 1) and squeeze "
-                    "after the load (see flash_decode/kernel.py)"))
-    return out
-
-
 def rule_pal002(mi: ModuleInfo) -> list[Finding]:
     """Every ``pallas_call`` site must route its block sizes through a
     budget/planning helper (``egnn_edge.budget``-style) or carry an explicit
@@ -719,7 +691,6 @@ RULES: list[Rule] = [
     _mk("DET002", "det-py-random", rule_det002),
     _mk("DET003", "det-wallclock", rule_det003),
     _mk("DON001", "donate-use-after", rule_don001),
-    _mk("PAL001", "pallas-bare-int-index", rule_pal001),
     _mk("PAL002", "pallas-unplanned-blocks", rule_pal002),
     _mk("PAL003", "pallas-scratch-dtype", rule_pal003),
 ]

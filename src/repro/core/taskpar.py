@@ -222,8 +222,6 @@ def mtp_value_and_grad_shardmap(model: MultiTaskModel, mesh: Mesh,
     identical to the pjit path (head grads carry the 1/n_tasks factor of the
     mean-over-tasks loss); per_task_loss is (n_tasks,), each entry averaged
     over that task's data sub-group."""
-    from jax.experimental.shard_map import shard_map
-
     ax_t = mtp.task_axis
     ax_d = tuple(mtp.data_axes)
     n_t = mtp.n_tasks
@@ -267,8 +265,8 @@ def mtp_value_and_grad_shardmap(model: MultiTaskModel, mesh: Mesh,
             jax.tree_util.tree_map(lambda l: P(), shared),
             jax.tree_util.tree_map(lambda l: shead(l.ndim), heads),
         )
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         l, per_task, gs, gh = fn(shared, heads, batch)
         return l, per_task, {"shared": gs, "heads": gh}
 

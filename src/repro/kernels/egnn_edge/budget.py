@@ -33,11 +33,11 @@ over-budget overrides raise.
 """
 from __future__ import annotations
 
+from repro.kernels.segment_sum.kernel import lane_tiles
+
 VMEM_BYTES = 16 << 20          # physical VMEM per TPU core
 VMEM_HEADROOM = 4 << 20        # Mosaic spills / semaphores / model slack
 VMEM_BUDGET = VMEM_BYTES - VMEM_HEADROOM
-
-_MIN_BLOCK = 8                 # sublane floor shared with autotune_blocks
 
 
 class VmemBudgetError(ValueError):
@@ -142,22 +142,24 @@ def check_blocks(A: int, E: int, H: int, block_e: int, block_h: int, *,
 def plan_blocks(A: int, E: int, H: int, *, itemsize: int = 4,
                 vmem_limit: int = VMEM_BUDGET) -> tuple[int, int]:
     """Plan ``(block_e, block_h)`` for the fused kernels: start from the
-    MXU-native 256-row tiles (clamped to the problem) and halve — ``block_h``
-    first, since the ``block_h·H`` weight tiles dominate at paper widths —
-    until the modeled resident set fits. Never returns an over-budget
-    config; raises ``VmemBudgetError`` if even the floor (8, 8) does not
-    fit (then the problem needs an A/H split this kernel doesn't have)."""
-    be = max(_MIN_BLOCK, min(256, E))
-    bh = max(_MIN_BLOCK, min(256, H))
-    while vmem_bytes(A, be, bh, H, itemsize=itemsize) > vmem_limit:
-        if bh > _MIN_BLOCK and bh >= be:
-            bh = max(_MIN_BLOCK, bh // 2)
-        elif be > _MIN_BLOCK:
-            be = max(_MIN_BLOCK, be // 2)
+    MXU-native 256-wide tiles (clamped to the problem) and shrink —
+    ``block_h`` first, since the ``block_h·H`` weight tiles dominate at
+    paper widths — through the lane-aligned sizes the TPU compiler accepts
+    (``lane_tiles``: the whole axis, or a multiple of 128) until the
+    modeled resident set fits. Never returns an over-budget config; raises
+    ``VmemBudgetError`` if even the smallest aligned pair does not fit
+    (then the problem needs an A/H split this kernel doesn't have)."""
+    es, hs = lane_tiles(E), lane_tiles(H)
+    ie = ih = 0
+    while vmem_bytes(A, es[ie], hs[ih], H, itemsize=itemsize) > vmem_limit:
+        if ih + 1 < len(hs) and (hs[ih] >= es[ie] or ie + 1 == len(es)):
+            ih += 1
+        elif ie + 1 < len(es):
+            ie += 1
         else:
             raise VmemBudgetError(
                 f"no (block_e, block_h) fits (A={A}, E={E}, H={H}, "
                 f"itemsize={itemsize}) in {vmem_limit / 2 ** 20:.1f} MiB — "
                 f"the A·H node state alone exceeds the budget; this shape "
                 f"needs a node-dimension split.")
-    return be, bh
+    return es[ie], hs[ih]

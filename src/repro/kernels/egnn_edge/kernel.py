@@ -31,12 +31,15 @@ the (block_e, H) f32 message row finishes before its single membership
 matmul; edge blocks sequential above it accumulate the (A, H) node scratch,
 flushed on the batch's last step.
 
-Masked/pad edges arrive with ``dst >= A`` (routed by ``ops.egnn_edge_agg``)
-and are excluded from the membership tile; their gather indices are clamped
-so the loads stay in bounds. Ragged ``E % block_e`` is padded with the
-sentinel; ragged ``H % block_h`` is padded with ZERO weight columns/rows —
-``silu(0) @ 0-rows`` contributes exactly nothing, and the pad columns of
-the weight-grad outputs are sliced away by the wrapper.
+Masked/pad edges arrive with ``src = dst = A`` (routed by
+``ops.egnn_edge_agg``). Endpoint rows are gathered by membership matmul
+(``segment_sum.gather_rows``: Mosaic cannot lower a row gather), so a pad
+edge gathers zero rows, and the same sentinel keeps it out of every
+scatter. Edge indices enter as (B, 1, E): an edge block is a (1, block_e)
+lane row, the layout the TPU compiler tiles. Ragged ``E % block_e`` is
+padded with the sentinel; ragged ``H % block_h`` is padded with ZERO weight
+columns/rows — ``silu(0) @ 0-rows`` contributes exactly nothing, and the
+pad columns of the weight-grad outputs are sliced away by the wrapper.
 
 Backward (``egnn_edge_fused_bwd``) — residual-recompute contract: the
 ``custom_vjp`` saves ONLY the primal inputs (h, pos, src, dst, edge_mask,
@@ -58,7 +61,13 @@ re-gathers h_i/h_j/x_i/x_j, re-derives d², recomputes the φ_e fc0 slice
     inside the ``block_h`` budget).
 
 Masked/pad edges produce exact zeros in every cotangent because ``dm`` (the
-gather of the upstream cotangent) is zeroed before anything multiplies it.
+gather of the upstream cotangent at ``dst``) is a zero row for them, and
+everything below multiplies it.
+
+The one-hot gathers and scatters of f32 values run at
+``Precision.HIGHEST`` so they stay exact (``exact_precision``); every other
+in-kernel dot (φ_e fc0/fc1 and the chain rule) follows the ambient
+``jax_default_matmul_precision``, as the XLA aggregation paths do.
 
 VMEM budgets are not estimated here — ``budget.py`` is the itemized,
 unit-tested model (``tests/test_egnn_budget.py``), and ``ops.py`` plans or
@@ -77,7 +86,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.egnn_edge.budget import check_blocks
-from repro.kernels.segment_sum.kernel import accumulate_tile, resolve_interpret
+from repro.kernels.segment_sum.kernel import (accumulate_tile, gather_rows,
+                                             resolve_interpret)
 
 
 def _pad_h_blocks(nh, bh, H, w0i, w0j, w0d, b0, w1):
@@ -94,16 +104,26 @@ def _pad_h_blocks(nh, bh, H, w0i, w0j, w0d, b0, w1):
 
 
 def _gather_edge_tile(src, dst, h, pos):
-    """Clamped endpoint gathers for one edge tile (pad edges load row A-1;
-    masked out of every sum by the ``>= A`` sentinel downstream)."""
-    A = h.shape[0]
-    sc = jnp.minimum(src, A - 1)
-    dc = jnp.minimum(dst, A - 1)
-    hi = jnp.take(h, sc, axis=0)              # (BE, H)
-    hj = jnp.take(h, dc, axis=0)
-    xi = jnp.take(pos, sc, axis=0)            # (BE, 3) f32
-    xj = jnp.take(pos, dc, axis=0)
-    return sc, dc, hi, hj, xi - xj
+    """Endpoint rows of one edge tile by membership matmul; src/dst are
+    (1, BE) rows. Pad edges (index >= A) gather zero rows and are kept out
+    of every sum by the same sentinel downstream."""
+    hi = gather_rows(src, h)                                  # (BE, H)
+    hj = gather_rows(dst, h)
+    diff = gather_rows(src, pos) - gather_rows(dst, pos)      # (BE, 3) f32
+    return hi, hj, diff
+
+
+def _fc0(hi, hj, d2, w0i, w0j, w0d, b0):
+    """φ_e fc0, H-block slice j of the *virtual* concat [hi | hj | d2]: the
+    weight arrives pre-split into its three row blocks (no (BE, 2H+1)
+    tensor) and pre-tiled into its output columns (no (H, H) tile). The
+    input-H contraction runs whole inside one f32-accumulated matmul pair
+    (Mosaic needs a 32-bit accumulator). Forward and backward both call
+    this, so the recomputed z rounds exactly as the forward's did."""
+    cd = hi.dtype
+    z = (jnp.dot(hi, w0i, preferred_element_type=jnp.float32)
+         + jnp.dot(hj, w0j, preferred_element_type=jnp.float32))
+    return z.astype(cd) + d2.astype(cd) * w0d + b0           # (BE, bh) cd
 
 
 def _edge_kernel(src_ref, dst_ref, h_ref, pos_ref, w0i_ref, w0j_ref, w0d_ref,
@@ -115,33 +135,30 @@ def _edge_kernel(src_ref, dst_ref, h_ref, pos_ref, w0i_ref, w0j_ref, w0d_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    src = src_ref[0]                      # (BE,) int32, >= A marks pad
+    src = src_ref[0]                      # (1, BE) int32, >= A marks pad
     dst = dst_ref[0]
     h = h_ref[0]                          # (A, H) compute dtype
     pos = pos_ref[0].astype(jnp.float32)  # (A, 3)
     A = h.shape[0]
     cd = h.dtype
 
-    _, _, hi, hj, diff = _gather_edge_tile(src, dst, h, pos)
-    d2 = jnp.sum(diff ** 2, axis=-1, keepdims=True).astype(cd)  # (BE, 1)
+    hi, hj, diff = _gather_edge_tile(src, dst, h, pos)
+    d2 = jnp.sum(diff ** 2, axis=-1, keepdims=True)           # (BE, 1) f32
 
     @pl.when(jh == 0)
     def _init_row():
         m_acc[...] = jnp.broadcast_to(
             b1_ref[...].astype(jnp.float32), m_acc.shape)
 
-    # φ_e fc0, H-block slice j of the *virtual* concat [hi | hj | d2]: the
-    # weight arrives pre-split into its three row blocks (no (BE, 2H+1)
-    # tensor) and pre-tiled into its output columns (no (H, H) tile). The
-    # input-H contraction runs whole inside this one matmul.
-    z = (hi @ w0i_ref[...] + hj @ w0j_ref[...]
-         + d2 * w0d_ref[...] + b0_ref[...])                   # (BE, bh) cd
+    z = _fc0(hi, hj, d2, w0i_ref[...], w0j_ref[...], w0d_ref[...],
+             b0_ref[...])                                     # (BE, bh) cd
     # fc1 K-split: fold this h-block straight into the f32 message row
-    m_acc[...] += (jax.nn.silu(z) @ w1_ref[...]).astype(jnp.float32)
+    # (SiLU in f32: Mosaic cannot lower a bf16 logistic)
+    s = jax.nn.silu(z.astype(jnp.float32)).astype(cd)
+    m_acc[...] += jnp.dot(s, w1_ref[...], preferred_element_type=jnp.float32)
 
     # membership matmul (MXU): pad edges carry dst >= A, which matches no
-    # node-id column (shared scatter-transpose tile with
-    # repro.kernels.segment_sum)
+    # node-id row (shared scatter tile with repro.kernels.segment_sum)
     @pl.when(jh == nh - 1)
     def _aggregate():
         accumulate_tile(dst, m_acc[...], acc_ref, ib=0, bn=A)
@@ -156,7 +173,7 @@ def _edge_kernel(src_ref, dst_ref, h_ref, pos_ref, w0i_ref, w0j_ref, w0d_ref,
 def egnn_edge_fused(h, pos, src, dst, w0i, w0j, w0d, b0, w1, b1, *,
                     block_e=256, block_h=256, interpret=None):
     """Fused forward. h: (B, A, H) compute-dtype node features; pos:
-    (B, A, 3); src/dst: (B, E) int32 with >= A marking masked/pad edges
+    (B, A, 3); src/dst: (B, E) int32 with A marking masked/pad edges
     (route them before calling — see ``ops.egnn_edge_agg``); φ_e fc0 weight
     pre-split into w0i (H,H), w0j (H,H), w0d (1,H), plus b0 (1,H), fc1
     w1 (H,H), b1 (1,H). ``block_h`` tiles the φ_e inner hidden axis (see
@@ -176,8 +193,8 @@ def egnn_edge_fused(h, pos, src, dst, w0i, w0j, w0d, b0, w1, b1, *,
         # pad sentinel A: matches no node id, contributes nothing
         src = jnp.pad(src, ((0, 0), (0, pe)), constant_values=A)
         dst = jnp.pad(dst, ((0, 0), (0, pe)), constant_values=A)
-    src = src.astype(jnp.int32)
-    dst = dst.astype(jnp.int32)
+    src = src.astype(jnp.int32)[:, None, :]
+    dst = dst.astype(jnp.int32)[:, None, :]
     w0i, w0j, w0d, b0, w1 = _pad_h_blocks(nh, bh, H, w0i, w0j, w0d, b0, w1)
 
     kern = functools.partial(_edge_kernel, ne=ne, nh=nh)
@@ -185,8 +202,8 @@ def egnn_edge_fused(h, pos, src, dst, w0i, w0j, w0d, b0, w1, b1, *,
         kern,
         grid=(B, ne, nh),
         in_specs=[
-            pl.BlockSpec((1, be), lambda b, je, jh: (b, je)),      # src
-            pl.BlockSpec((1, be), lambda b, je, jh: (b, je)),      # dst
+            pl.BlockSpec((1, 1, be), lambda b, je, jh: (b, 0, je)),  # src
+            pl.BlockSpec((1, 1, be), lambda b, je, jh: (b, 0, je)),  # dst
             pl.BlockSpec((1, A, H), lambda b, je, jh: (b, 0, 0)),  # h
             pl.BlockSpec((1, A, 3), lambda b, je, jh: (b, 0, 0)),  # pos
             pl.BlockSpec((H, bh), lambda b, je, jh: (0, jh)),      # w0i
@@ -234,7 +251,7 @@ def _edge_bwd_kernel(src_ref, dst_ref, h_ref, pos_ref, g_ref,
     def _init_b1():
         acc_b1[...] = jnp.zeros_like(acc_b1)
 
-    src = src_ref[0]                      # (BE,) int32, >= A marks pad
+    src = src_ref[0]                      # (1, BE) int32, >= A marks pad
     dst = dst_ref[0]
     h = h_ref[0]                          # (A, H) compute dtype
     pos = pos_ref[0].astype(jnp.float32)  # (A, 3)
@@ -247,28 +264,26 @@ def _edge_bwd_kernel(src_ref, dst_ref, h_ref, pos_ref, g_ref,
     # contract in the module docstring). z_j is recomputed in the compute
     # dtype — identical dot shape and rounding to the forward kernel —
     # then the chain rule runs in f32.
-    sc, dc, hi, hj, diff = _gather_edge_tile(src, dst, h, pos)
+    hi, hj, diff = _gather_edge_tile(src, dst, h, pos)
     d2f = jnp.sum(diff ** 2, axis=-1, keepdims=True)          # (BE, 1) f32
-    z = (hi @ w0i_ref[...] + hj @ w0j_ref[...]
-         + d2f.astype(cd) * w0d_ref[...] + b0_ref[...])       # (BE, bh) cd
+    z = _fc0(hi, hj, d2f, w0i_ref[...], w0j_ref[...], w0d_ref[...],
+             b0_ref[...])                                     # (BE, bh) cd
     zf = z.astype(jnp.float32)
     sig = jax.nn.sigmoid(zf)
     s = zf * sig                                              # silu(z), f32
 
-    # --- dm: gather of g at the destination, zeroed on masked/pad edges.
-    # Every downstream cotangent is a product with dm (or dz), so masked
-    # edges contribute exact zeros everywhere below.
-    valid = dst < A
-    gm = jnp.take(g, dc, axis=0).astype(jnp.float32)          # (BE, H)
-    dm = jnp.where(valid[:, None], gm, 0.0)
+    # --- dm: gather of g at the destination, a zero row on masked/pad
+    # edges (dst >= A). Every downstream cotangent is a product with dm (or
+    # dz), so masked edges contribute exact zeros everywhere below.
+    dm = gather_rows(dst, g.astype(jnp.float32))              # (BE, H)
 
     w1f = w1_ref[...].astype(jnp.float32)                     # (bh, H)
     ds = jax.lax.dot_general(dm, w1f, (((1,), (1,)), ((), ())))  # (BE, bh)
     dz = ds * (sig * (1.0 + zf * (1.0 - sig)))                # silu'(z)
 
     # --- node cotangents: this h-block's slice of the chain, scattered via
-    # the shared membership-matmul tile (clamped indices always hit a real
-    # row; masked rows are exact zeros) and accumulated across ALL h-blocks
+    # the shared membership-matmul tile (pad indices hit no row; their
+    # cotangents are exact zeros anyway) and accumulated across ALL h-blocks
     # in the per-graph (A, H)/(A, 3) scratch
     w0if = w0i_ref[...].astype(jnp.float32)                   # (H, bh)
     w0jf = w0j_ref[...].astype(jnp.float32)
@@ -277,10 +292,10 @@ def _edge_bwd_kernel(src_ref, dst_ref, h_ref, pos_ref, g_ref,
     dhj = jax.lax.dot_general(dz, w0jf, (((1,), (1,)), ((), ())))
     dd2 = jnp.sum(dz * w0df, axis=-1, keepdims=True)          # (BE, 1)
     ddiff = 2.0 * diff * dd2                                  # (BE, 3) = d xi
-    accumulate_tile(sc, dhi, acc_dh, ib=0, bn=A)
-    accumulate_tile(dc, dhj, acc_dh, ib=0, bn=A)
-    accumulate_tile(sc, ddiff, acc_dpos, ib=0, bn=A)
-    accumulate_tile(dc, -ddiff, acc_dpos, ib=0, bn=A)
+    accumulate_tile(src, dhi, acc_dh, ib=0, bn=A)
+    accumulate_tile(dst, dhj, acc_dh, ib=0, bn=A)
+    accumulate_tile(src, ddiff, acc_dpos, ib=0, bn=A)
+    accumulate_tile(dst, -ddiff, acc_dpos, ib=0, bn=A)
 
     # --- φ_e weight cotangents, H-block slice: reduce over this edge tile
     hif = hi.astype(jnp.float32)
@@ -342,8 +357,8 @@ def egnn_edge_fused_bwd(g, h, pos, src, dst, w0i, w0j, w0d, b0, w1, *,
         pe = ne * be - E
         src = jnp.pad(src, ((0, 0), (0, pe)), constant_values=A)
         dst = jnp.pad(dst, ((0, 0), (0, pe)), constant_values=A)
-    src = src.astype(jnp.int32)
-    dst = dst.astype(jnp.int32)
+    src = src.astype(jnp.int32)[:, None, :]
+    dst = dst.astype(jnp.int32)[:, None, :]
     w0i, w0j, w0d, b0, w1 = _pad_h_blocks(nh, bh, H, w0i, w0j, w0d, b0, w1)
 
     kern = functools.partial(_edge_bwd_kernel, nb=B, ne=ne, nh=nh)
@@ -361,8 +376,8 @@ def egnn_edge_fused_bwd(g, h, pos, src, dst, w0i, w0j, w0d, b0, w1, *,
         kern,
         grid=(B, nh, ne),
         in_specs=[
-            pl.BlockSpec((1, be), lambda b, jh, je: (b, je)),      # src
-            pl.BlockSpec((1, be), lambda b, jh, je: (b, je)),      # dst
+            pl.BlockSpec((1, 1, be), lambda b, jh, je: (b, 0, je)),  # src
+            pl.BlockSpec((1, 1, be), lambda b, jh, je: (b, 0, je)),  # dst
             pl.BlockSpec((1, A, H), lambda b, jh, je: (b, 0, 0)),  # h
             pl.BlockSpec((1, A, 3), lambda b, jh, je: (b, 0, 0)),  # pos
             pl.BlockSpec((1, A, H), lambda b, jh, je: (b, 0, 0)),  # g

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.segment_sum.kernel import resolve_interpret
+
 NEG_INF = -1e30
 
 VMEM_BUDGET = (16 << 20) - (4 << 20)   # physical VMEM minus Mosaic headroom
@@ -100,8 +102,9 @@ def _fa_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
                                              "block_q", "block_k", "interpret"))
 def flash_attention_bhsd(q, k, v, q_pos, k_pos, *, causal=True, window=0,
-                         scale=None, block_q=128, block_k=128, interpret=True):
-    """q: (B,H,Sq,D); k,v: (B,K,Sk,D); H % K == 0. Returns (B,H,Sq,D)."""
+                         scale=None, block_q=128, block_k=128, interpret=None):
+    """q: (B,H,Sq,D); k,v: (B,K,Sk,D); H % K == 0. Returns (B,H,Sq,D).
+    ``interpret=None`` runs compiled on TPU, interpreted elsewhere."""
     B, H, Sq, D = q.shape
     K, Sk = k.shape[1], k.shape[2]
     G = H // K
@@ -141,6 +144,6 @@ def flash_attention_bhsd(q, k, v, q_pos, k_pos, *, causal=True, window=0,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q_pos, k_pos, q, k, v)
     return out[:, :, :Sq]

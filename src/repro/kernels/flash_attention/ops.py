@@ -7,7 +7,7 @@ from .kernel import flash_attention_bhsd
 
 
 def flash_attention(q, k, v, *, q_pos, k_pos, causal=True, window=0,
-                    scale=None, block_q=128, block_k=128, interpret=True):
+                    scale=None, block_q=128, block_k=128, interpret=None):
     """q: (B,Sq,H,D); k,v: (B,Sk,K,D) -> (B,Sq,H,D)."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

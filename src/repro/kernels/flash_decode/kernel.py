@@ -25,7 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.segment_sum.kernel import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -72,18 +73,10 @@ def _fd_kernel(qpos_ref, kp_ref, q_ref, k_ref, v_ref,
 
     def body(i, carry):
         m, l, acc = carry
-        # full-Slice index tuples only: jax 0.4.37's interpret-mode discharge
-        # rule chokes on bare ints inside pl.load indices (it probes
-        # ``.shape`` on every non-Slice entry), so the unit leading dims are
-        # loaded as dslice(0, 1) and squeezed after the load
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.dslice(i * bk, bk), slice(None))
-                    )[0, 0].astype(jnp.float32)     # (BK, D)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.dslice(i * bk, bk), slice(None))
-                    )[0, 0].astype(jnp.float32)
-        kp = pl.load(kp_ref, (pl.dslice(0, 1),
-                              pl.dslice(i * bk, bk)))[0]  # (BK,)
+        rows = pl.ds(i * bk, bk)
+        k = k_ref[0, 0, rows, :].astype(jnp.float32)    # (BK, D)
+        v = v_ref[0, 0, rows, :].astype(jnp.float32)
+        kp = kp_ref[0, rows]                            # (BK,)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G,BK)
         dpos = qpos - kp
         mask = (kp > -(10 ** 8)) & (dpos >= 0)
@@ -111,9 +104,10 @@ def _fd_kernel(qpos_ref, kp_ref, q_ref, k_ref, v_ref,
 @functools.partial(jax.jit, static_argnames=("window", "scale", "n_splits",
                                              "block_k", "interpret"))
 def flash_decode_partials(q, k, v, q_pos, k_pos, *, window=0, scale=None,
-                          n_splits=8, block_k=512, interpret=True):
+                          n_splits=8, block_k=512, interpret=None):
     """q: (B,H,D) one token per sequence; k,v: (B,K,S,D); k_pos: (B,S).
-    Returns partials (m, l, acc) with a trailing split dim."""
+    Returns partials (m, l, acc) with a trailing split dim.
+    ``interpret=None`` runs compiled on TPU, interpreted elsewhere."""
     B, H, D = q.shape
     K, S = k.shape[1], k.shape[2]
     G = H // K
@@ -154,7 +148,7 @@ def flash_decode_partials(q, k, v, q_pos, k_pos, *, window=0, scale=None,
             pl.BlockSpec((1, 1, G, 1, D), lambda b, h, s: (b, h, 0, s, 0)),
         ],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q_pos.reshape(B, 1).astype(jnp.int32), k_pos.astype(jnp.int32),
       qg, k, v)
     return m, l, acc

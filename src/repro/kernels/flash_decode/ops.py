@@ -7,7 +7,7 @@ from .kernel import combine_partials, flash_decode_partials
 
 
 def flash_decode(q, k, v, *, q_pos, k_pos, window=0, scale=None,
-                 n_splits=8, block_k=512, interpret=True):
+                 n_splits=8, block_k=512, interpret=None):
     """q: (B,1,H,D); k,v: (B,S,K,D); q_pos: (B,) or scalar; k_pos: (B,S) or
     (S,). Returns (B,1,H,D)."""
     B, _, H, D = q.shape

@@ -3,7 +3,7 @@
 GPU frameworks implement scatter-add with atomics; TPU has none, so the
 operation is re-thought for the MXU (the DESIGN.md "adapt, don't port" item):
 tile (edges x nodes), build the one-hot membership tile in VMEM from the
-destination-index block, and accumulate ``one_hotᵀ @ messages`` as a matmul.
+destination-index block, and accumulate ``one_hot @ messages`` as a matmul.
 
 Two entry points:
 
@@ -28,7 +28,7 @@ is discarded, and any ``dst >= num_node_blocks*BN`` matches no row at all.
 The internal ragged-E pad sentinel is ``num_node_blocks*BN + 1`` — strictly
 above every node id a tile can generate (asserted below, not assumed).
 
-VMEM budget at BN=128, BE=256, F=896: membership tile (256x128 f32) 128 KiB,
+VMEM budget at BN=128, BE=256, F=896: membership tile (128x256 f32) 128 KiB,
 message tile (256x896 f32) 896 KiB, accumulator (128x896 f32) 448 KiB —
 ≈1.5 MiB resident.
 
@@ -71,47 +71,88 @@ def _block_geometry(n_nodes: int, E: int, block_n: int, block_e: int):
     return bn, be, nb, ne, sentinel
 
 
+def membership(idx, n, *, base=0):
+    """(n, BE) f32 one-hot of a (1, BE) int32 index row: entry (r, e) is 1
+    where ``idx[e] == base + r``. Indices outside ``base .. base+n-1`` (the
+    pad sentinels) give an all-zero column. The row layout keeps the edge
+    axis on lanes, so the tile is a sublane iota against a broadcast row."""
+    rows = base + jax.lax.broadcasted_iota(jnp.int32, (n, idx.shape[-1]), 0)
+    return (rows == idx).astype(jnp.float32)
+
+
+def exact_precision(dtype):
+    """Dot precision for the membership matmuls: a one-hot gather/scatter of
+    f32 values must not round them through one bf16 MXU pass."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
 def accumulate_tile(dst, msg, acc_ref, *, ib, bn):
-    """One (edge-block x node-block) scatter-transpose tile: membership
-    one-hot as an MXU matmul (``one_hotᵀ @ msg``), accumulated into the f32
-    scratch. This is the shared TPU replacement for scatter-add — used by
-    both segment-sum entry points here and by the fused EGNN edge kernel's
-    forward aggregation and backward ``d_h``/``d_x`` scatters
-    (``repro.kernels.egnn_edge``). Masking is by index, per the sentinel
-    contract: any ``dst`` outside this tile's ``ib*bn .. ib*bn+bn-1`` id
-    range matches no one-hot column and contributes nothing."""
-    node_ids = ib * bn + jax.lax.broadcasted_iota(
-        jnp.int32, (dst.shape[0], bn), 1)
-    onehot = (dst[:, None] == node_ids).astype(jnp.float32)   # (BE, BN)
-    acc_ref[...] += jax.lax.dot_general(
-        onehot, msg, (((0,), (0,)), ((), ())))
+    """One (edge-block x node-block) scatter tile: ``acc += onehot @ msg``
+    on the MXU, with ``onehot = membership(dst, bn, base=ib*bn)``. This is
+    the shared TPU replacement for scatter-add — used by both segment-sum
+    entry points here and by the fused EGNN edge kernel's forward
+    aggregation and backward ``d_h``/``d_x`` scatters
+    (``repro.kernels.egnn_edge``). ``dst`` is a (1, BE) row; masking is by
+    index, per the sentinel contract: any ``dst`` outside this tile's
+    ``ib*bn .. ib*bn+bn-1`` id range contributes nothing."""
+    onehot = membership(dst, bn, base=ib * bn)                # (BN, BE)
+    acc_ref[...] += jnp.dot(onehot, msg,
+                            precision=exact_precision(msg.dtype),
+                            preferred_element_type=jnp.float32)
 
 
-_accumulate_tile = accumulate_tile  # back-compat alias
+def gather_rows(idx, x):
+    """Rows ``x[idx[e]]`` for a (1, BE) index row -> (BE, F): the transpose
+    of ``accumulate_tile`` (``onehotᵀ @ x``). Mosaic cannot lower a
+    row gather inside a kernel, the MXU can do it as a matmul. Sentinel
+    indices ``>= x.shape[0]`` gather an all-zero row."""
+    onehot = membership(idx, x.shape[0]).astype(x.dtype)     # (N, BE)
+    rows = jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
+                               precision=exact_precision(x.dtype),
+                               preferred_element_type=jnp.float32)
+    return rows.astype(x.dtype)       # exact: one nonzero term per row
+
+
+LANE = 128      # TPU lane width: an index row block is a multiple of it or
+SUBLANE = 8     # the whole row; row blocks of a tile are multiples of 8
+MAX_LANE_TILE = 256     # the MXU-native tile width the planners start from
+
+
+def lane_tiles(dim: int) -> list[int]:
+    """Block sizes the TPU compiler accepts on a lane (last) axis of length
+    ``dim``, largest first, none above ``MAX_LANE_TILE``: the whole axis when
+    it is no longer than that, then multiples of ``LANE``."""
+    first = min(dim, MAX_LANE_TILE)
+    return [first] + [t for t in range(MAX_LANE_TILE - LANE, 0, -LANE)
+                      if t < first]
 
 
 def autotune_blocks(n_nodes: int, E: int, F: int, *, extra_bytes: int = 0,
                     vmem_limit: int = 8 << 20) -> tuple[int, int]:
     """Heuristic (block_n, block_e) for the membership-matmul kernels: start
-    from the MXU-native 128x256 tile and halve ``block_e`` until the resident
-    f32 working set (node accumulator + message tile + membership tile, plus
-    ``extra_bytes`` for caller-resident buffers such as the fused kernel's
-    φ_e weights) fits the VMEM budget. Callers override via the
-    ``kernel_block_n`` / ``kernel_block_e`` config knobs
+    from the MXU-native 128x256 tile and shrink ``block_e`` through the
+    lane-aligned sizes (``lane_tiles``), then ``block_n`` by sublane
+    multiples, until the resident f32 working set (node accumulator +
+    message tile + membership tile, plus ``extra_bytes`` for caller-resident
+    buffers) fits the VMEM budget. Raises ``ValueError`` when no aligned
+    tile fits (wide F needs an F split this kernel does not have). Callers
+    override via the ``kernel_block_n`` / ``kernel_block_e`` config knobs
     (``repro.configs.base.ArchConfig``)."""
-    bn = max(8, min(128, n_nodes))
-    be = max(8, min(256, E))
+    bn = max(SUBLANE, min(128, n_nodes))
 
-    def resident():
+    def resident(bn, be):
         return extra_bytes + 4 * (bn * F + be * F + be * bn)
 
-    while be > 8 and resident() > vmem_limit:
-        be //= 2
-    # never emit an over-budget config: once the edge tile hits the sublane
-    # floor, keep shrinking the node tile (wide-F problems otherwise sail
-    # past the budget with be pinned at 8)
-    while bn > 8 and resident() > vmem_limit:
-        bn //= 2
+    for be in lane_tiles(E):
+        if resident(bn, be) <= vmem_limit:
+            return bn, be
+    be = lane_tiles(E)[-1]
+    while bn > SUBLANE and resident(bn, be) > vmem_limit:
+        bn = max(SUBLANE, bn // 2 // SUBLANE * SUBLANE)
+    if resident(bn, be) > vmem_limit:
+        raise ValueError(
+            f"no lane-aligned segment-sum tile fits (n_nodes={n_nodes}, "
+            f"E={E}, F={F}) in {vmem_limit / 2 ** 20:.1f} MiB of VMEM")
     return bn, be
 
 
@@ -123,8 +164,8 @@ def _ss_kernel(dst_ref, msg_ref, o_ref, acc_ref, *, bn, ne):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _accumulate_tile(dst_ref[...], msg_ref[...].astype(jnp.float32),
-                     acc_ref, ib=ib, bn=bn)
+    accumulate_tile(dst_ref[...], msg_ref[...].astype(jnp.float32),
+                    acc_ref, ib=ib, bn=bn)       # dst block (1, BE)
 
     @pl.when(je == ne - 1)
     def _flush():
@@ -137,21 +178,22 @@ def segment_sum_2d(messages, dst, n_nodes: int, *, block_n=128, block_e=256,
                    interpret=None):
     """messages: (E, F); dst: (E,) int32 in [0, n_nodes) or >= n_nodes for
     masked/pad edges (see the sentinel contract in the module docstring).
-    Returns (n_nodes, F)."""
+    Returns (n_nodes, F). The indices enter as a (1, E) row: the TPU
+    compiler refuses a 1-D block that is not a multiple of 1024."""
     E, F = messages.shape
     bn, be, nb, ne, sentinel = _block_geometry(n_nodes, E, block_n, block_e)
     if ne * be != E:
         pe = ne * be - E
         messages = jnp.pad(messages, ((0, pe), (0, 0)))
         dst = jnp.pad(dst, (0, pe), constant_values=sentinel)
-    dst = dst.astype(jnp.int32)
+    dst = dst.astype(jnp.int32)[None, :]
 
     kern = functools.partial(_ss_kernel, bn=bn, ne=ne)
     out = pl.pallas_call(
         kern,
         grid=(nb, ne),
         in_specs=[
-            pl.BlockSpec((be,), lambda ib, je: (je,)),
+            pl.BlockSpec((1, be), lambda ib, je: (0, je)),
             pl.BlockSpec((be, F), lambda ib, je: (je, 0)),
         ],
         out_specs=pl.BlockSpec((bn, F), lambda ib, je: (ib, 0)),
@@ -170,8 +212,8 @@ def _ss_batched_kernel(dst_ref, msg_ref, o_ref, acc_ref, *, bn, ne):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _accumulate_tile(dst_ref[0], msg_ref[0].astype(jnp.float32),
-                     acc_ref, ib=ib, bn=bn)
+    accumulate_tile(dst_ref[0], msg_ref[0].astype(jnp.float32),
+                    acc_ref, ib=ib, bn=bn)       # dst block (1, 1, BE)
 
     @pl.when(je == ne - 1)
     def _flush():
@@ -185,21 +227,22 @@ def segment_sum_batched(messages, dst, n_nodes: int, *, block_n=128,
     """messages: (B, E, F); dst: (B, E) int32 in [0, n_nodes) or >= n_nodes
     for masked/pad edges. Returns (B, n_nodes, F). B is the leading
     (parallel) grid dimension — each graph reuses the same node/edge tiling
-    as ``segment_sum_2d``."""
+    as ``segment_sum_2d``. The indices enter as (B, 1, E) so an edge block
+    is a lane-axis row the TPU compiler can tile."""
     B, E, F = messages.shape
     bn, be, nb, ne, sentinel = _block_geometry(n_nodes, E, block_n, block_e)
     if ne * be != E:
         pe = ne * be - E
         messages = jnp.pad(messages, ((0, 0), (0, pe), (0, 0)))
         dst = jnp.pad(dst, ((0, 0), (0, pe)), constant_values=sentinel)
-    dst = dst.astype(jnp.int32)
+    dst = dst.astype(jnp.int32)[:, None, :]
 
     kern = functools.partial(_ss_batched_kernel, bn=bn, ne=ne)
     out = pl.pallas_call(
         kern,
         grid=(B, nb, ne),
         in_specs=[
-            pl.BlockSpec((1, be), lambda b, ib, je: (b, je)),
+            pl.BlockSpec((1, 1, be), lambda b, ib, je: (b, 0, je)),
             pl.BlockSpec((1, be, F), lambda b, ib, je: (b, je, 0)),
         ],
         out_specs=pl.BlockSpec((1, bn, F), lambda b, ib, je: (b, ib, 0)),

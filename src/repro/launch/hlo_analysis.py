@@ -290,12 +290,9 @@ def _instr_traffic_full(comps, comp: Computation, ins: Instr) -> float:
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """jax-version-tolerant ``compiled.cost_analysis()``: newer jax returns
-    the per-device dict directly, jax 0.4.x wraps it in a 1-element list."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a plain dict (XLA's per-device
+    estimate of flops and bytes)."""
+    return dict(compiled.cost_analysis())
 
 
 def analyze_hlo(text: str) -> dict:

@@ -9,16 +9,10 @@ from jax.sharding import Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh across jax versions: axis_types= (and AxisType) only
-    exist on newer releases; fall back to the plain call."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler, as pjit expects)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -1,7 +1,10 @@
 """Runnable trainer CLI — a thin argparse front-end over ``repro.engine``.
 
-  # the paper's GFM, MTP x DDP over the host devices:
-  PYTHONPATH=src python -m repro.launch.train --mode gfm --steps 200
+  # the paper's GFM at its published widths (H=866, 4 layers, 5 branches):
+  PYTHONPATH=src python -m repro.launch.train --mode gfm --no-smoke --steps 200
+
+  # the same model cut down to smoke scale (the default):
+  PYTHONPATH=src python -m repro.launch.train --mode gfm --steps 20
 
   # any assigned LM arch at smoke scale:
   PYTHONPATH=src python -m repro.launch.train --mode lm --arch qwen1.5-0.5b --steps 50
@@ -17,12 +20,19 @@ from repro import configs
 from repro.data.lm_data import make_lm_sources
 from repro.data.synthetic_atoms import generate_all
 from repro.engine import Session, SessionConfig
+from repro.launch import compile_cache
+
+
+def arch_for(args):
+    """The ArchConfig a run trains: the published config with ``--no-smoke``,
+    the arch's smoke cut otherwise."""
+    name = "hydragnn-gfm" if args.mode == "gfm" else args.arch
+    return configs.get_smoke(name) if args.smoke else configs.get(name)
 
 
 def session_for(args) -> Session:
+    cfg = arch_for(args)
     if args.mode == "gfm":
-        cfg = configs.get_smoke("hydragnn-gfm") if args.smoke else \
-            configs.get("hydragnn-gfm").replace(gnn_hidden=128, head_hidden=64)
         data = list(generate_all(args.samples, max_atoms=cfg.max_atoms,
                                  max_edges=cfg.max_edges).items())[:cfg.n_tasks]
         sources = [dict(species=s.species, pos=s.pos, edge_src=s.edge_src,
@@ -39,7 +49,6 @@ def session_for(args) -> Session:
         return Session.from_config(scfg, sources=sources,
                                    task_names=[k for k, _ in data])
 
-    cfg = configs.get_smoke(args.arch)
     if args.mode == "lm-mtl":
         cfg = cfg.replace(n_tasks=args.tasks)
         sources = make_lm_sources(cfg.n_tasks, 64, args.seq, cfg.vocab)
@@ -57,8 +66,8 @@ def session_for(args) -> Session:
     return Session.from_config(scfg, sources=source)
 
 
-def main():
-    ap = argparse.ArgumentParser()
+def parse_args(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--mode", default="gfm", choices=["gfm", "lm", "lm-mtl"])
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--tasks", type=int, default=4)
@@ -70,9 +79,17 @@ def main():
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the arch's smoke cut (--no-smoke: the "
+                         "published config)")
     ap.add_argument("--ckpt", default=None)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
     with session_for(args) as session:
         result = session.run()
     return result.final_loss

@@ -129,7 +129,7 @@ def sdpa(q, k, v, *, q_pos, k_pos, causal=True, window=0, scale=None,
         from repro.kernels.flash_attention import ops as fa_ops
         return fa_ops.flash_attention(q, k, v, q_pos=q_pos, k_pos=k_pos,
                                       causal=causal, window=window, scale=scale,
-                                      interpret=kw.get("interpret", True))
+                                      interpret=kw.get("interpret"))
     return sdpa_chunked(q, k, v, q_pos=q_pos, k_pos=k_pos, causal=causal,
                         window=window, scale=scale,
                         q_chunk=kw.get("q_chunk", 512), k_chunk=kw.get("k_chunk", 1024))

@@ -1,8 +1,8 @@
 """Seeded-sweep property tests for the data pipeline.
 
-This container has no ``hypothesis`` (jax 0.4.37 host), so these sweeps
-draw their own randomized configurations from seeded NumPy generators —
-deterministic, ≥ 50 drawn configurations per property — and assert the
+These sweeps draw their own randomized configurations from seeded NumPy
+generators rather than ``hypothesis`` — deterministic, ≥ 50 drawn
+configurations per property, the same every run — and assert the
 subsystem invariants the docs promise:
 
   * ``BucketingBatcher`` never drops content: trimming only removes

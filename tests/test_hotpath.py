@@ -1,5 +1,6 @@
-"""ISSUE-2 hot-path parity suite (deterministic — no hypothesis in this
-container, so this is the always-on coverage for the aggregation kernels):
+"""Hot-path parity suite (deterministic, fixed seeds: the always-on
+coverage for the aggregation kernels, beside the hypothesis properties in
+tests/test_kernels_segment.py):
 
   * one-hot ("jnp") vs scatter-add vs batched Pallas segment-sum agree to
     fp32 tolerance on batched shapes with pad edges AND pad nodes;
@@ -215,10 +216,11 @@ def test_kernel_block_config_knob_threads_through():
             np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-4), g_t, g_d)
 
 
-@pytest.mark.parametrize("impl", ["scatter", "fused"])
+@pytest.mark.parametrize("impl", ["scatter", "pallas", "fused"])
 def test_egnn_apply_grads_match_reference(impl):
-    """The new default and the fused custom_vjp both differentiate like the
-    one-hot reference — the train step is safe on every impl."""
+    """The default, the Pallas segment-sum custom_vjp and the fused
+    custom_vjp all differentiate like the one-hot reference — the train
+    step is safe on every impl."""
     cfg = _gfm_cfg(gnn_layers=1)
     batch = _gfm_batch(cfg, seed=3)
     params = gnn.egnn_init(jax.random.PRNGKey(2), cfg)

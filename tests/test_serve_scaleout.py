@@ -397,6 +397,16 @@ SCRIPT = textwrap.dedent("""
     except ValueError:
         res["sharded"]["uneven_raises"] = True
     sh.close()
+    # four rows per device: each shard has the reference's own batch shape
+    sh4 = ServeSession(params, cfg, spec=spec, max_batch=8 * 4, mesh=mesh8,
+                       max_wait_ms=2.0)
+    outs4 = [f.result(timeout=300)
+             for f in [sh4.submit(sm, head=t) for t, sm in jobs]]
+    res["sharded_x4"] = {
+        "parity": all(match(o, r) for o, r in zip(outs4, refs)),
+        "plan": sh4.stats()["plan"],
+    }
+    sh4.close()
     ref_srv.close()
     print("RESULT " + json.dumps(res))
 """)
@@ -442,6 +452,11 @@ def test_replica_close_drains_everything(result):
 def test_sharded_rows_bitwise_match_single_device(result):
     assert result["sharded"]["parity"] is True
     assert result["sharded"]["plan"] == {"mode": "sharded", "devices": 8}
+
+
+def test_sharded_four_rows_per_device_match_single_device(result):
+    assert result["sharded_x4"]["parity"] is True
+    assert result["sharded_x4"]["plan"] == {"mode": "sharded", "devices": 8}
 
 
 def test_sharded_compile_budget_is_the_bucket_grid(result):
