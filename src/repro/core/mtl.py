@@ -29,12 +29,14 @@ from .taskpar import MultiTaskModel
 # ---------------------------------------------------------------------------
 
 def gfm_loss_terms(e_pred, f_pred, batch_t, force_weight=1.0):
-    """Masked MSE on energy-per-atom + forces for one task's sub-batch."""
-    nm = batch_t["node_mask"]
-    e_err = jnp.mean(jnp.square(e_pred - batch_t["energy"]))
-    f_err = jnp.sum(jnp.square(f_pred - batch_t["forces"]) * nm[..., None]) / \
-        jnp.maximum(jnp.sum(nm) * 3.0, 1.0)
-    return e_err + force_weight * f_err, e_err, f_err
+    """Masked MSE on energy-per-atom + forces for one task's sub-batch.
+    Its device ops carry the named scope ``loss``."""
+    with jax.named_scope("loss"):
+        nm = batch_t["node_mask"]
+        e_err = jnp.mean(jnp.square(e_pred - batch_t["energy"]))
+        f_err = jnp.sum(jnp.square(f_pred - batch_t["forces"])
+                        * nm[..., None]) / jnp.maximum(jnp.sum(nm) * 3.0, 1.0)
+        return e_err + force_weight * f_err, e_err, f_err
 
 
 def make_gfm_mtl(cfg, n_tasks: int, force_weight: float = 1.0,

@@ -38,6 +38,8 @@ from __future__ import annotations
 import queue
 import threading
 
+from repro.profiling import span
+
 
 class Prefetcher:
     """Wrap any batcher (the ``next_batch()`` contract) with a depth-``depth``
@@ -46,6 +48,10 @@ class Prefetcher:
     transform: optional callable applied to each batch ON THE PRODUCER
     THREAD — pass ``plan.shard_batch`` (or ``jax.device_put``) so host->
     device transfer overlaps the running step.
+
+    Host spans (``repro.profiling``): ``data.draw`` (the batcher) and
+    ``data.place`` (the transform) on the producer thread, ``data.wait``
+    (blocked for a batch) on the consumer's.
 
     Exceptions in the producer (including inside ``transform``) are captured
     and re-raised from ``next_batch()``. Use as a context manager or call
@@ -104,13 +110,15 @@ class Prefetcher:
                 if self._fault is not None:
                     exc, self._fault = self._fault, None
                     raise exc
-                b = self.batcher.next_batch()
+                with span("data.draw"):
+                    b = self.batcher.next_batch()
                 # snapshot BEFORE transform (transform is placement, not
                 # stream position) and after the draw: restoring to this
                 # snapshot replays the stream from the NEXT batch
                 st = self.batcher.state() if self._trackable else None
                 if self.transform is not None:
-                    b = self.transform(b)
+                    with span("data.place"):
+                        b = self.transform(b)
                 self._put((b, st))
         except BaseException as e:  # propagate to the consumer
             if isinstance(e, StopIteration):
@@ -143,7 +151,8 @@ class Prefetcher:
             except queue.Empty:
                 raise RuntimeError("Prefetcher is closed") from self._err
         else:
-            item, st = self._q.get()
+            with span("data.wait"):
+                item, st = self._q.get()
         if item is self._DONE:
             self._stop.set()
             raise self._err

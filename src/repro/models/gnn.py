@@ -103,28 +103,38 @@ def egnn_apply(params: Params, batch: dict, *, cfg, impl=None) -> jnp.ndarray:
     src, dst = batch["edge_src"], batch["edge_dst"]
     nm, em = batch["node_mask"], batch["edge_mask"]
     B, A = species.shape
-    h = embed(params["embed"], species, cd) * nm[..., None].astype(cd)
 
     def gather(x, idx):
         return jnp.take_along_axis(x, idx[..., None], axis=1)
 
-    for i in range(cfg.gnn_layers):
-        lp = params[f"layer{i}"]
-        if impl == "fused":
-            from repro.kernels.egnn_edge import ops as edge_ops
-            agg = edge_ops.egnn_edge_agg(h, pos, src, dst, em, lp["phi_e"],
-                                         compute_dtype=cd, block_e=be,
-                                         block_h=bh)
-        else:
-            hi = gather(h, jnp.minimum(src, A - 1))
-            hj = gather(h, jnp.minimum(dst, A - 1))
-            xi = gather(pos, jnp.minimum(src, A - 1))
-            xj = gather(pos, jnp.minimum(dst, A - 1))
-            d2 = jnp.sum((xi - xj) ** 2, -1, keepdims=True).astype(cd)
-            m = mlp_apply(lp["phi_e"], jnp.concatenate([hi, hj, d2], -1),
-                          "silu", cd)
-            agg = segment_sum_nodes(m, dst, A, edge_mask=em, impl=impl,
-                                    block_n=bn, block_e=be)
-        upd = mlp_apply(lp["phi_h"], jnp.concatenate([h, agg], -1), "silu", cd)
-        h = (h + upd) * nm[..., None].astype(cd)
+    # named scopes: the device trace and the optimized HLO's op_name read
+    # egnn/embed, egnn/layer{i}/message, egnn/layer{i}/node_update, forward
+    # and backward alike; they change only metadata
+    with jax.named_scope("egnn"):
+        with jax.named_scope("embed"):
+            h = embed(params["embed"], species, cd) * nm[..., None].astype(cd)
+        for i in range(cfg.gnn_layers):
+            lp = params[f"layer{i}"]
+            with jax.named_scope(f"layer{i}/message"):
+                if impl == "fused":
+                    from repro.kernels.egnn_edge import ops as edge_ops
+                    agg = edge_ops.egnn_edge_agg(
+                        h, pos, src, dst, em, lp["phi_e"], compute_dtype=cd,
+                        block_e=be, block_h=bh)
+                else:
+                    hi = gather(h, jnp.minimum(src, A - 1))
+                    hj = gather(h, jnp.minimum(dst, A - 1))
+                    xi = gather(pos, jnp.minimum(src, A - 1))
+                    xj = gather(pos, jnp.minimum(dst, A - 1))
+                    d2 = jnp.sum((xi - xj) ** 2, -1, keepdims=True).astype(cd)
+                    m = mlp_apply(lp["phi_e"],
+                                  jnp.concatenate([hi, hj, d2], -1), "silu",
+                                  cd)
+                    agg = segment_sum_nodes(m, dst, A, edge_mask=em,
+                                            impl=impl, block_n=bn,
+                                            block_e=be)
+            with jax.named_scope(f"layer{i}/node_update"):
+                upd = mlp_apply(lp["phi_h"], jnp.concatenate([h, agg], -1),
+                                "silu", cd)
+                h = (h + upd) * nm[..., None].astype(cd)
     return h

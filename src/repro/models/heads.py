@@ -33,14 +33,17 @@ def stacked_branches_init(key, cfg, n_tasks: int) -> Params:
 
 
 def branch_apply(bp: Params, node_feats, node_mask, *, cfg):
-    """node_feats: (B,A,hid) -> (energy_per_atom: (B,), forces: (B,A,3))."""
+    """node_feats: (B,A,hid) -> (energy_per_atom: (B,), forces: (B,A,3)).
+    Its device ops carry the named scope ``heads``."""
     cd = cfg.compute_dtype
-    nm = node_mask[..., None].astype(cd)
-    n = jnp.maximum(node_mask.sum(-1, keepdims=True).astype(jnp.float32), 1.0)
-    pooled = (node_feats * nm).sum(1) / n.astype(cd)       # masked mean-pool
-    e = mlp_apply(bp["energy"], pooled, "silu", cd)[..., 0]  # (B,)
-    f = mlp_apply(bp["force"], node_feats, "silu", cd) * nm  # (B,A,3)
-    return e.astype(jnp.float32), f.astype(jnp.float32)
+    with jax.named_scope("heads"):
+        nm = node_mask[..., None].astype(cd)
+        n = jnp.maximum(node_mask.sum(-1, keepdims=True).astype(jnp.float32),
+                        1.0)
+        pooled = (node_feats * nm).sum(1) / n.astype(cd)   # masked mean-pool
+        e = mlp_apply(bp["energy"], pooled, "silu", cd)[..., 0]   # (B,)
+        f = mlp_apply(bp["force"], node_feats, "silu", cd) * nm   # (B,A,3)
+        return e.astype(jnp.float32), f.astype(jnp.float32)
 
 
 def stacked_branches_apply(bp: Params, node_feats, node_mask, *, cfg):

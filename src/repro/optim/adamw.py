@@ -35,6 +35,10 @@ def adamw(lr, *, b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.01,
                           v=jax.tree_util.tree_map(zeros, params))
 
     def update(grads, state, params):
+        with jax.named_scope("optimizer"):
+            return _update(grads, state, params)
+
+    def _update(grads, state, params):
         step = state.step + 1
         if grad_clip > 0:
             gnorm = global_norm(grads)

@@ -181,10 +181,13 @@ class SizeBinnedBatcher:
     policy:    optional ``AdaptivePolicy`` — replaces the fixed release
                knobs with measured-rate per-bin ones (release shape is
                still the static ``max_batch``).
+    metrics:   optional ``ServeMetrics``: each release's ``assemble`` runs
+               as its stage ``assemble`` (span and ``assembly`` reservoir).
     """
 
     def __init__(self, *, max_batch: int = 8, max_wait: float = 0.005,
-                 clock=time.monotonic, policy: AdaptivePolicy | None = None):
+                 clock=time.monotonic, policy: AdaptivePolicy | None = None,
+                 metrics=None):
         assert max_batch >= 1 and max_wait >= 0.0
         if policy is not None:
             assert policy.max_batch == max_batch, \
@@ -193,6 +196,7 @@ class SizeBinnedBatcher:
         self.max_wait = max_wait
         self._clock = clock
         self.policy = policy
+        self.metrics = metrics
         self._bins: dict[tuple, list[Request]] = {}   # (bucket, head) -> reqs
 
     # per-bin effective knobs: fixed, unless a policy is measuring
@@ -220,7 +224,10 @@ class SizeBinnedBatcher:
     def _release(self, key: tuple, bin_: list[Request]) -> AssembledBatch:
         if self.policy is not None:
             self.policy.observe_release(key, len(bin_))
-        return assemble(bin_, key[0], self.max_batch)
+        if self.metrics is None:
+            return assemble(bin_, key[0], self.max_batch)
+        with self.metrics.stage("assemble"):
+            return assemble(bin_, key[0], self.max_batch)
 
     def expired(self, now: float | None = None) -> list[AssembledBatch]:
         """Bins whose oldest request has waited past its wait budget,

@@ -163,6 +163,10 @@ class ServeSession:
             self._predict = self._sharded_predict(forward, mesh)
         self._exec: dict[tuple, object] = {}   # (bucket, head) -> callable
         self._shapes_compiled: set = set()
+        # the jitted forward's cache size when last counted (see
+        # _count_compilations); the worker and predict_one both call it
+        self._compiled = 0
+        self._compile_lock = threading.Lock()
         self._closed = False
         self._worker_error: BaseException | None = None
         # requests dequeued but not yet filed into the batcher: on a worker
@@ -184,7 +188,8 @@ class ServeSession:
     def _make_batcher(self) -> SizeBinnedBatcher:
         return SizeBinnedBatcher(max_batch=self.max_batch,
                                  max_wait=self._max_wait,
-                                 clock=self._clock, policy=self._policy)
+                                 clock=self._clock, policy=self._policy,
+                                 metrics=self.metrics)
 
     def _sharded_predict(self, forward, mesh):
         """jit the forward with rows data-parallel over the serving mesh and
@@ -337,46 +342,58 @@ class ServeSession:
 
     def _executable(self, bucket: tuple, head: int):
         """The per-(bucket, head) cache entry: the shared jitted forward
-        with this head's parameter slice bound. Counts a compilation only
-        when the bucket SHAPE is new — same-shape entries for other heads
-        reuse the compiled executable."""
+        with this head's parameter slice bound. Same-shape entries for
+        other heads reuse the compiled executable; every call counts what
+        it compiled (``_count_compilations``)."""
         key = (bucket, head)
         fn = self._exec.get(key)
         if fn is None:
-            if bucket not in self._shapes_compiled:
-                self._shapes_compiled.add(bucket)
-                self.metrics.inc("compilations")
+            self._shapes_compiled.add(bucket)
             hp = self._heads[head]
             shared = self._shared
 
             def fn(batch, _p=self._predict, _s=shared, _h=hp):
-                return _p(_s, _h, batch)
+                out = _p(_s, _h, batch)
+                self._count_compilations()
+                return out
 
             self._exec[key] = fn
         return fn
 
+    def _count_compilations(self):
+        """Add to the ``compilations`` counter what the jitted forward's
+        own cache grew by since the last count: every XLA compilation, a
+        recompile of a warmed shape (another dtype, say) included."""
+        with self._compile_lock:
+            n = self._predict._cache_size()
+            grew, self._compiled = n - self._compiled, n
+        if grew:
+            self.metrics.inc("compilations", grew)
+
     def _execute(self, ab: AssembledBatch):
         """Run one assembled batch and scatter rows to futures."""
-        t0 = self._clock()
+        m = self.metrics
         try:
-            e, f = self._executable(ab.bucket, ab.head)(ab.batch)
-            e, f = np.asarray(e), np.asarray(f)   # blocks until ready
+            with m.stage("compute"):
+                with m.stage("dispatch"):
+                    e, f = self._executable(ab.bucket, ab.head)(ab.batch)
+                with m.stage("readback"):
+                    e, f = np.asarray(e), np.asarray(f)   # blocks until ready
         except BaseException as err:
             for r in ab.requests:
                 r.future.set_exception(err)
-            self.metrics.inc("failed", len(ab.requests))
+            m.inc("failed", len(ab.requests))
             return
-        t1 = self._clock()
-        self.metrics.observe("compute", t1 - t0)
-        self.metrics.inc("batches")
-        self.metrics.inc("batch_slots", self.max_batch)
-        self.metrics.inc("batch_real", ab.n_real)
-        for i, r in enumerate(ab.requests):
-            r.t_done = self._clock()
-            r.future.set_result(
-                {"energy": float(e[i]), "forces": f[i, :r.n_atoms]})
-            self.metrics.observe("e2e", r.t_done - r.t_submit)
-        self.metrics.inc("completed", ab.n_real)
+        with m.stage("scatter"):
+            m.inc("batches")
+            m.inc("batch_slots", self.max_batch)
+            m.inc("batch_real", ab.n_real)
+            for i, r in enumerate(ab.requests):
+                r.t_done = self._clock()
+                r.future.set_result(
+                    {"energy": float(e[i]), "forces": f[i, :r.n_atoms]})
+                m.observe("e2e", r.t_done - r.t_submit)
+            m.inc("completed", ab.n_real)
 
     def _file(self, req) -> AssembledBatch | None:
         req.t_dequeue = self._clock()
@@ -389,11 +406,7 @@ class ServeSession:
                 f"queue, past its max_queue_wait deadline"))
             self.metrics.inc("shed_deadline")
             return None
-        t0 = self._clock()
-        ab = self.batcher.add(req)
-        if ab is not None:
-            self.metrics.observe("assembly", self._clock() - t0)
-        return ab
+        return self.batcher.add(req)
 
     def _serve_loop(self):
         try:
@@ -404,29 +417,26 @@ class ServeSession:
                 # coarse tick so close() is observed promptly
                 timeout = 0.05 if deadline is None \
                     else min(max(deadline, 0.0), 0.05)
-                req = self.queue.get(timeout=timeout)
+                with self.metrics.stage("poll"):
+                    req = self.queue.get(timeout=timeout)
                 if req is not None:
                     # greedy drain: file the WHOLE backlog before computing.
                     # Under load, dequeued requests are usually already past
                     # their deadline (they aged in the queue), so filing one
                     # at a time would flush every bin one-deep; filing the
                     # backlog first lets bins reach max_batch occupancy.
-                    self._inflight = [req] + self.queue.drain()
-                    ready = []
-                    while self._inflight:
-                        ab = self._file(self._inflight[0])
-                        self._inflight.pop(0)
-                        if ab is not None:
-                            ready.append(ab)
+                    with self.metrics.stage("file"):
+                        self._inflight = [req] + self.queue.drain()
+                        ready = []
+                        while self._inflight:
+                            ab = self._file(self._inflight[0])
+                            self._inflight.pop(0)
+                            if ab is not None:
+                                ready.append(ab)
                     for ab in ready:
                         self._execute(ab)
-                t0 = self._clock()
-                expired = self.batcher.expired(self._clock())
-                if expired:
-                    dt = (self._clock() - t0) / len(expired)
-                    for ab in expired:
-                        self.metrics.observe("assembly", dt)
-                        self._execute(ab)
+                for ab in self.batcher.expired(self._clock()):
+                    self._execute(ab)
             # graceful drain: admissions are closed, so the queue can only
             # shrink — run everything left through the compiled path
             for req in self.queue.drain():

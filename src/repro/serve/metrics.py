@@ -13,6 +13,11 @@ the lifecycle diagram):
   * ``compute``    — the compiled forward, blocked until ready;
   * ``e2e``        — submit() to the request future resolving.
 
+The serve worker's per-batch stages go through ``ServeMetrics.stage``, the
+one instrumentation point: each opens the host span ``serve.<stage>``
+(``repro.profiling.span``) and, where the stage has a reservoir above,
+times it there (``STAGE_RESERVOIR``).
+
 Percentiles come from a **deterministic reservoir**: fixed capacity,
 Vitter's algorithm R driven by a seeded ``np.random.default_rng`` — two
 runs over the same observation stream produce the same reservoir, so
@@ -27,7 +32,13 @@ import time
 
 import numpy as np
 
+from repro.profiling import span
+
 STAGES = ("queue_wait", "assembly", "compute", "e2e")
+# the serve worker's stages that are timed into a reservoir of STAGES:
+# ``compute`` runs from dispatch to the end of readback, and holds the
+# stages ``dispatch`` and ``readback``
+STAGE_RESERVOIR = {"assemble": "assembly", "compute": "compute"}
 
 
 class Reservoir:
@@ -68,6 +79,27 @@ class Reservoir:
         return out
 
 
+class _Timed:
+    """A stage timed into a reservoir inside its span (see
+    ``ServeMetrics.stage``); a plain class, as the worker opens several per
+    batch and a generator-based context manager costs a few microseconds."""
+    __slots__ = ("metrics", "reservoir", "span", "t0")
+
+    def __init__(self, metrics, reservoir: str, span_):
+        self.metrics, self.reservoir, self.span = metrics, reservoir, span_
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = self.metrics._clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is None:
+            self.metrics.observe(self.reservoir,
+                                 self.metrics._clock() - self.t0)
+        return self.span.__exit__(exc_type, exc, tb)
+
+
 class ServeMetrics:
     """Counters + per-stage latency reservoirs for one ``ServeSession``.
 
@@ -80,7 +112,8 @@ class ServeMetrics:
                                                   restart_worker() recoveries
       batches                                   — compiled executions run
       batch_slots / batch_real                  — padded vs occupied rows
-      compilations                              — distinct compiled shapes
+      compilations                              — XLA compilations of the
+                                                  serving forward
       routed / failovers                        — replica-scheduler decisions
                                                   (multi-device mode only)
     ``snapshot()`` returns a plain nested dict (JSON-serializable) with
@@ -113,6 +146,15 @@ class ServeMetrics:
     def observe(self, stage: str, seconds: float):
         with self._lock:
             self.stages[stage].add(seconds * 1e3)   # stored as ms
+
+    def stage(self, name: str):
+        """One stage of the serve worker, as a context manager: the span
+        ``serve.<name>`` and, where ``STAGE_RESERVOIR`` names one, the
+        stage's time into that reservoir. A stage that raises is not timed.
+        An untimed stage with no profiler running is the shared no-op."""
+        reservoir = STAGE_RESERVOIR.get(name)
+        s = span("serve." + name)
+        return s if reservoir is None else _Timed(self, reservoir, s)
 
     def snapshot(self) -> dict:
         with self._lock:
