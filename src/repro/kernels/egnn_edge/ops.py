@@ -109,7 +109,7 @@ def egnn_edge_agg(h, pos, src, dst, edge_mask, phi_e, *, compute_dtype=None,
                   block_e=None, block_h=None, interpret=None):
     """Fused EGNN message + aggregation: (B, A, H) node features in,
     (B, A, H) aggregated messages out. Drop-in for the unfused
-    gather/φ_e/segment-sum sequence in ``egnn_apply`` (numerics: ``ref.py``),
+    ``repro.models.gnn.message_agg`` (numerics: ``ref.py``),
     differentiable end-to-end via the fused backward kernel.
     ``block_e``/``block_h``: None plans against the VMEM budget model
     (``cfg.kernel_block_e`` / ``cfg.kernel_block_h`` override via

@@ -1,12 +1,14 @@
 """Pure-jnp oracle for the fused EGNN edge kernel.
 
-Exactly the unfused message hot path of ``repro.models.gnn.egnn_apply``
-(gather -> d² -> φ_e via ``mlp_apply`` on the materialized concat ->
-scatter segment-sum), so kernel-vs-ref parity is also kernel-vs-model
-parity. ``jax.grad`` through this function is likewise the oracle for the
-fused BACKWARD kernel (``kernel.egnn_edge_fused_bwd``): the custom_vjp in
-``ops.py`` must match it within tolerance in every cotangent
-(tests/test_hotpath.py paper-shape parity suite)."""
+The message path in its concat form: gather -> d² -> φ_e via ``mlp_apply``
+on the materialized (B, E, 2H+1) concat -> scatter segment-sum. The model's
+non-fused path (``repro.models.gnn.message_agg``) computes the same sum with
+fc0 projected onto the atoms before the gather, and is held to this oracle
+as the kernel is (tests/test_hotpath.py). ``jax.grad`` through this
+function is likewise the oracle for the fused BACKWARD kernel
+(``kernel.egnn_edge_fused_bwd``): the custom_vjp in ``ops.py`` must match
+it within tolerance in every cotangent (tests/test_hotpath.py paper-shape
+parity suite)."""
 from __future__ import annotations
 
 import jax.numpy as jnp

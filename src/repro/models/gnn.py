@@ -11,8 +11,18 @@ shape, rather than partitioning one monolithic graph):
   node_mask:  (B, A)    bool
   edge_mask:  (B, E)    bool
 
+Each layer's message is φ_e(h_i, h_j, d²_ij) on every edge slot, summed into
+its destination atom. On the non-fused paths φ_e's first dense is projected
+onto the atoms before the gather: fc0 is linear, so its (2H+1, H) weight
+splits into the h_i, h_j and d² row blocks, the two H-row blocks multiply the
+(B, A, H) node features once per atom, and the edge slots gather the
+projections (``message_agg``). fc0 then costs A rows per graph instead of
+E, and the (B, E, 2H+1) concat never exists. SiLU and the later denses run
+per edge slot as before.
+
 Message aggregation is a segment-sum — the MPNN hot spot. Implementations
-(selected per call or via ``cfg.segment_sum_impl``):
+(selected per call or via ``cfg.segment_sum_impl``; the first three share
+the node-side fc0 above):
 
   * ``"scatter"`` (default) — ``zeros.at[b, dst].add(msg)``: one XLA
     scatter-add, O(E·F) work. Fastest lowering on CPU/GPU and what XLA:TPU
@@ -22,8 +32,8 @@ Message aggregation is a segment-sum — the MPNN hot spot. Implementations
   * ``"pallas"``  — blocked mask-matmul MXU kernel
     (``repro.kernels.segment_sum``), batched grid over B.
   * ``"fused"``   — the full message hot path (gather -> d² -> φ_e MLP ->
-    masked segment-sum) in one Pallas kernel (``repro.kernels.egnn_edge``),
-    never materializing the (B,E,2H+1) concat in HBM.
+    masked segment-sum) in one Pallas kernel (``repro.kernels.egnn_edge``);
+    it keeps φ_e's fc0 per edge, split by rows inside the kernel.
 """
 from __future__ import annotations
 
@@ -32,7 +42,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .common import KeyGen, Params, dense, embedding_init, embed
+from .common import (ACT, KeyGen, Params, cast, dense, embedding_init,
+                     embed)
 from .mlp import mlp_init, mlp_apply
 
 SEGMENT_SUM_IMPLS = ("scatter", "jnp", "pallas", "fused")
@@ -81,6 +92,39 @@ def egnn_init(key, cfg) -> Params:
     return p
 
 
+def _gather(x, idx):
+    """x: (B, A, F), idx: (B, E) in [0, A) -> (B, E, F)."""
+    return jnp.take_along_axis(x, idx[..., None], axis=1)
+
+
+def message_agg(h, pos, src, dst, edge_mask, phi_e: Params, *,
+                compute_dtype, impl="scatter", block_n=None, block_e=None):
+    """One layer's non-fused message path -> (B, A, H) aggregated messages.
+
+    Same arguments and result as ``egnn_edge_agg`` (``repro.kernels.
+    egnn_edge``); ``impl`` picks the segment-sum ("scatter" | "jnp" |
+    "pallas"). φ_e's fc0 is linear, so W·[h_i; h_j; d²] + b = (h W_a)[i]
+    + (h W_b)[j] + d²·w_d + b, with W_a, W_b, w_d the row blocks of
+    ``fc0.w`` ((2H+1, H)): the two projections run on the (B, A, H) node
+    rows and the edge slots gather them."""
+    cd = compute_dtype
+    A, H = h.shape[1], h.shape[2]
+    sc = jnp.minimum(src, A - 1)
+    dc = jnp.minimum(dst, A - 1)
+    pos = pos.astype(jnp.float32)
+    d2 = jnp.sum((_gather(pos, sc) - _gather(pos, dc)) ** 2, -1,
+                 keepdims=True).astype(cd)
+    fc0 = phi_e["fc0"]
+    w = cast(fc0["w"], cd)
+    x = cast(h, cd)
+    m = (_gather(x @ w[:H], sc) + _gather(x @ w[H:2 * H], dc)
+         + d2 * w[2 * H] + cast(fc0["b"], cd))
+    for i in range(1, len(phi_e)):
+        m = dense(phi_e[f"fc{i}"], ACT["silu"](m), cd)
+    return segment_sum_nodes(m, dst, A, edge_mask=edge_mask, impl=impl,
+                             block_n=block_n, block_e=block_e)
+
+
 def egnn_apply(params: Params, batch: dict, *, cfg, impl=None) -> jnp.ndarray:
     """-> node features (B, A, hidden). Invariant (distance-based) features.
     impl selects the message-aggregation path ("scatter" | "jnp" | "pallas" |
@@ -102,10 +146,6 @@ def egnn_apply(params: Params, batch: dict, *, cfg, impl=None) -> jnp.ndarray:
     pos = batch["pos"].astype(jnp.float32)
     src, dst = batch["edge_src"], batch["edge_dst"]
     nm, em = batch["node_mask"], batch["edge_mask"]
-    B, A = species.shape
-
-    def gather(x, idx):
-        return jnp.take_along_axis(x, idx[..., None], axis=1)
 
     # named scopes: the device trace and the optimized HLO's op_name read
     # egnn/embed, egnn/layer{i}/message, egnn/layer{i}/node_update, forward
@@ -122,17 +162,9 @@ def egnn_apply(params: Params, batch: dict, *, cfg, impl=None) -> jnp.ndarray:
                         h, pos, src, dst, em, lp["phi_e"], compute_dtype=cd,
                         block_e=be, block_h=bh)
                 else:
-                    hi = gather(h, jnp.minimum(src, A - 1))
-                    hj = gather(h, jnp.minimum(dst, A - 1))
-                    xi = gather(pos, jnp.minimum(src, A - 1))
-                    xj = gather(pos, jnp.minimum(dst, A - 1))
-                    d2 = jnp.sum((xi - xj) ** 2, -1, keepdims=True).astype(cd)
-                    m = mlp_apply(lp["phi_e"],
-                                  jnp.concatenate([hi, hj, d2], -1), "silu",
-                                  cd)
-                    agg = segment_sum_nodes(m, dst, A, edge_mask=em,
-                                            impl=impl, block_n=bn,
-                                            block_e=be)
+                    agg = message_agg(h, pos, src, dst, em, lp["phi_e"],
+                                      compute_dtype=cd, impl=impl,
+                                      block_n=bn, block_e=be)
             with jax.named_scope(f"layer{i}/node_update"):
                 upd = mlp_apply(lp["phi_h"], jnp.concatenate([h, agg], -1),
                                 "silu", cd)

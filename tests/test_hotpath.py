@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Literal
 
 from repro.configs.base import ArchConfig
 from repro.data.synthetic_atoms import generate_all, to_batch_dict
@@ -115,15 +116,143 @@ def test_fused_edge_kernel_matches_ref(block_e):
                                atol=1e-5, rtol=1e-5)
 
 
-def test_egnn_apply_all_impls_agree():
+def _pad_batch(cfg, B=3, A=7, E=24, seed=0):
+    """Hand-made batch with pad atoms, pad edges at the sentinel A, and a
+    last graph with atoms but no real edge."""
+    rng = np.random.default_rng(seed)
+    n_atoms, n_edges = [5, 3, 2], [14, 4, 0]
+    species = np.zeros((B, A), np.int32)
+    pos = np.zeros((B, A, 3), np.float32)
+    src = np.full((B, E), A, np.int32)
+    dst = np.full((B, E), A, np.int32)
+    for b, (na, ne) in enumerate(zip(n_atoms, n_edges)):
+        species[b, :na] = rng.integers(1, cfg.n_species, na)
+        pos[b, :na] = rng.normal(0.0, 1.5, (na, 3))
+        src[b, :ne] = rng.integers(0, na, ne)
+        dst[b, :ne] = rng.integers(0, na, ne)
+    return {"species": jnp.asarray(species), "pos": jnp.asarray(pos),
+            "edge_src": jnp.asarray(src), "edge_dst": jnp.asarray(dst),
+            "node_mask": jnp.asarray(species > 0),
+            "edge_mask": jnp.asarray(src < A)}
+
+
+def _egnn_concat_oracle(params, batch, cfg):
+    """egnn_apply with φ_e on the materialized (B, E, 2H+1) concat: each
+    layer's message through ``egnn_edge_agg_ref``."""
+    from repro.models.mlp import mlp_apply
+    cd = cfg.compute_dtype
+    nm = batch["node_mask"][..., None].astype(cd)
+    h = gnn.embed(params["embed"], batch["species"], cd) * nm
+    for i in range(cfg.gnn_layers):
+        lp = params[f"layer{i}"]
+        agg = egnn_edge_agg_ref(h, batch["pos"], batch["edge_src"],
+                                batch["edge_dst"], batch["edge_mask"],
+                                lp["phi_e"], compute_dtype=cd)
+        upd = mlp_apply(lp["phi_h"], jnp.concatenate([h, agg], -1), "silu",
+                        cd)
+        h = (h + upd) * nm
+    return h
+
+
+@pytest.mark.parametrize("case", ["synthetic", "pads"])
+@pytest.mark.parametrize("impl", ["scatter", "jnp", "pallas", "fused"])
+def test_egnn_apply_all_impls_agree(impl, case):
+    """Every impl matches the concat-form oracle in float32, forward and in
+    the gradients with respect to the parameters and the positions."""
     cfg = _gfm_cfg()
-    batch = _gfm_batch(cfg)
+    batch = _gfm_batch(cfg) if case == "synthetic" else _pad_batch(cfg)
     params = gnn.egnn_init(jax.random.PRNGKey(1), cfg)
-    ref = gnn.egnn_apply(params, batch, cfg=cfg, impl="jnp")
-    for impl in ("scatter", "pallas", "fused"):
-        got = gnn.egnn_apply(params, batch, cfg=cfg, impl=impl)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5, err_msg=impl)
+    ref = _egnn_concat_oracle(params, batch, cfg)
+    got = gnn.egnn_apply(params, batch, cfg=cfg, impl=impl)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5, err_msg=impl)
+    probe = jax.random.normal(jax.random.PRNGKey(5), ref.shape, jnp.float32)
+
+    def loss(fn, p, pos):
+        return jnp.sum(fn(p, {**batch, "pos": pos}) * probe)
+
+    g_ref = jax.grad(lambda p, x: loss(
+        lambda pp, bb: _egnn_concat_oracle(pp, bb, cfg), p, x),
+        argnums=(0, 1))(params, batch["pos"])
+    g_got = jax.grad(lambda p, x: loss(
+        lambda pp, bb: gnn.egnn_apply(pp, bb, cfg=cfg, impl=impl), p, x),
+        argnums=(0, 1))(params, batch["pos"])
+    jax.tree_util.tree_map(
+        lambda a, b: _assert_close_scaled(a, b, 1e-5, impl), g_got, g_ref)
+
+
+@pytest.mark.parametrize("impl", ["scatter", "jnp", "pallas"])
+def test_message_agg_matches_concat_ref(impl):
+    """The node-side fc0 path of one layer against the concat-form oracle,
+    forward and gradients with respect to h, pos and φ_e, with masked edges,
+    sentinel edges and a graph with no real edge."""
+    h, pos, src, dst, em, phi_e, gw = _paper_case(B=3, E=96, A=16, H=32)
+    em = em.at[2].set(False)
+
+    def loss(fn, hh, pp, ww):
+        out = fn(hh, pp, src, dst, em, ww, compute_dtype=jnp.float32)
+        return jnp.sum(out * gw), out
+
+    def ours(*a, **kw):
+        return gnn.message_agg(*a, impl=impl, **kw)
+
+    (_, got), g_got = jax.value_and_grad(
+        lambda *a: loss(ours, *a), argnums=(0, 1, 2), has_aux=True)(
+            h, pos, phi_e)
+    (_, ref), g_ref = jax.value_and_grad(
+        lambda *a: loss(egnn_edge_agg_ref, *a), argnums=(0, 1, 2),
+        has_aux=True)(h, pos, phi_e)
+    _assert_close_scaled(got, ref, 1e-5, "forward")
+    np.testing.assert_array_equal(np.asarray(got[2]), 0.0)
+    for n, a, b in zip(("d_h", "d_pos", "d_phi_e"), g_got, g_ref):
+        jax.tree_util.tree_map(
+            lambda x, y, n=n: _assert_close_scaled(x, y, 1e-5, n), a, b)
+
+
+def _jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _jaxpr_eqns(inner)
+
+
+def test_phi_e_fc0_runs_on_the_atoms():
+    """The scatter path never forms a (.., 2H+1) value, forward or backward,
+    and φ_e's fc0 row blocks multiply (B, A, H) node rows, not edge rows."""
+    H, L = 8, 2
+    cfg = _gfm_cfg(gnn_hidden=H, gnn_layers=L)
+    batch = _pad_batch(cfg)
+    B, A = batch["species"].shape
+    params = gnn.egnn_init(jax.random.PRNGKey(0), cfg)
+
+    def fwd(p):
+        return gnn.egnn_apply(p, batch, cfg=cfg, impl="scatter")
+
+    for fn in (fwd, jax.grad(lambda p: jnp.sum(fwd(p) ** 2))):
+        closed = jax.make_jaxpr(fn)(params)
+        shapes = [getattr(v.aval, "shape", ()) for e in
+                  _jaxpr_eqns(closed.jaxpr) for v in e.outvars]
+        assert not [s for s in shapes if s and s[-1] == 2 * H + 1]
+
+    closed = jax.make_jaxpr(fwd)(params)
+    leaves, _ = jax.tree_util.tree_flatten_with_path(params)
+    fc0 = {v for (path, _), v in zip(leaves, closed.jaxpr.invars)
+           if "phi_e" in jax.tree_util.keystr(path)
+           and "fc0" in jax.tree_util.keystr(path)
+           and "'w'" in jax.tree_util.keystr(path)}
+    assert len(fc0) == L
+    lhs_shapes = []
+    for eqn in _jaxpr_eqns(closed.jaxpr):
+        if any(v in fc0 for v in eqn.invars if not isinstance(v, Literal)):
+            if eqn.primitive.name == "dot_general":
+                lhs_shapes.append(eqn.invars[0].aval.shape)
+            elif eqn.primitive.name in ("slice", "convert_element_type"):
+                fc0.update(eqn.outvars)     # a row block of fc0.w
+    assert lhs_shapes == [(B, A, H)] * (2 * L)
 
 
 def _paper_case(B=4, E=768, A=128, H=256, dtype=jnp.float32, seed=0):
