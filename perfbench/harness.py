@@ -5,7 +5,9 @@ A cell names a configuration and a traffic mix in ``BENCHMARK.json``. The
 harness finds the rest by those names:
 
   * ``perfbench/configs/<config>.json``: the configuration as it is run;
-    its ``reference`` key names the plain reference beside it;
+    its ``reference`` key names the plain reference of its architecture
+    (``perfbench/reference/common.py`` says what such a module brings),
+    from which the weights, the operation count and the checks come;
   * ``perfbench/traffic/<traffic>.json``: the mix's parameters; its
     ``runner`` key names the general runner in ``perfbench/runners/``;
   * ``perfbench/cells/<workload>.json``: the cell's correctness limits;
@@ -15,6 +17,7 @@ harness finds the rest by those names:
 from __future__ import annotations
 
 import contextlib
+import functools
 import importlib.util
 import json
 import math
@@ -42,6 +45,17 @@ def load_module(path: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_at(path: str):
+    return load_module(path, os.path.splitext(os.path.basename(path))[0])
+
+
+def reference(cfg: dict):
+    """The plain reference module that configuration ``cfg`` names, loaded
+    once per process."""
+    return _reference_at(os.path.join(BENCH_DIR, cfg["reference"]))
 
 
 def benchmark(root: str = ROOT) -> dict:

@@ -5,11 +5,9 @@ the program; the runners reach the program only through these helpers.
 """
 from __future__ import annotations
 
-from perfbench.weights import DTYPES
+import dataclasses
 
-# configuration-file keys that are sizes of the program's ArchConfig
-ARCH_KEYS = ("gnn_hidden", "gnn_layers", "head_hidden", "head_layers",
-             "n_tasks", "n_species", "max_atoms", "max_edges")
+from perfbench.weights import DTYPES
 
 
 def set_precision(cfg: dict):
@@ -22,12 +20,13 @@ def set_precision(cfg: dict):
 
 def arch(cfg: dict):
     """The program's ArchConfig for a configuration file: the program's own
-    entry ``cfg["arch"]`` with every size and dtype the file states."""
+    entry ``cfg["arch"]`` with every key of the file that names one of its
+    fields, but ``name``; a ``*_dtype`` is named by a key of ``DTYPES``."""
     from repro import configs
     base = configs.get(cfg["arch"])
-    return base.replace(**{k: cfg[k] for k in ARCH_KEYS},
-                        compute_dtype=DTYPES[cfg["compute_dtype"]],
-                        param_dtype=DTYPES[cfg["param_dtype"]])
+    fields = {f.name for f in dataclasses.fields(base)} - {"name"}
+    return base.replace(**{k: DTYPES[v] if k.endswith("_dtype") else v
+                           for k, v in cfg.items() if k in fields})
 
 
 def session(cfg: dict, traffic: dict, sources, names, *, seed: int):

@@ -19,6 +19,7 @@ import time
 import numpy as np
 
 from perfbench import atoms, devtrace, harness, openloop, program, weights
+from perfbench.reference import common
 
 WAIT_AFTER_CLOSE_S = 60.0
 
@@ -47,8 +48,6 @@ def run(*, config, traffic, limits, seed, seconds, trace, devices, t_start,
         log=harness.log, fault=None):
     import jax
 
-    ref = harness.load_module(
-        os.path.join(harness.BENCH_DIR, config["reference"]), "reference")
     names, sources = atoms.generate(harness.sources_spec(traffic))
     t_data = time.perf_counter()
     span_s = float(traffic["trace_seconds"]) if trace else float(seconds)
@@ -137,9 +136,10 @@ def run(*, config, traffic, limits, seed, seconds, trace, devices, t_start,
             rows[key] = rows[key][:, :a_cap]
         for key in ("edge_src", "edge_dst", "edge_mask"):
             rows[key] = rows[key][:, :e_cap]
-        ref_e, ref_f = ref.serve_readings(
-            weights.init_params(config, seed), rows,
-            [pool[i][0] for i in sample], config["gnn_layers"])
+        ref = harness.reference(config)
+        ref_e, ref_f = common.serve_readings(
+            ref.trunk, ref.branch, weights.init_params(config, seed), rows,
+            [pool[i][0] for i in sample], config)
         n_atoms = [int(sources[pool[i][0]]["node_mask"][pool[i][1]].sum())
                    for i in sample]
         e_gap, f_gap = answer_gaps(answers, ref_e, ref_f, n_atoms)

@@ -27,6 +27,7 @@ import time
 import numpy as np
 
 from perfbench import atoms, devtrace, harness, program, weights
+from perfbench.reference import common
 
 B1 = 0.9   # AdamW's first-moment decay, the program's and the reference's
 # steps dispatched ahead of the oldest unfinished one: enough to keep the
@@ -114,8 +115,6 @@ def run(*, config, traffic, limits, seed, seconds, trace, devices, t_start,
     import jax
     import jax.numpy as jnp
 
-    ref = harness.load_module(
-        os.path.join(harness.BENCH_DIR, config["reference"]), "reference")
     names, sources = atoms.generate(harness.sources_spec(traffic))
     marks = {"imports_and_data": time.perf_counter()}
     multitask = config["n_tasks"] > 1
@@ -232,10 +231,10 @@ def run(*, config, traffic, limits, seed, seconds, trace, devices, t_start,
     hp = {k: traffic[k] for k in ("lr", "warmup", "schedule_steps",
                                   "weight_decay")}
     t_ref = time.perf_counter()
-    readings = ref.train_readings(
-        weights.init_params(config, seed),
-        [jax.device_put(b) for b in host_batches], w, hp,
-        config["gnn_layers"])
+    ref = harness.reference(config)
+    readings = common.train_readings(
+        ref.trunk, ref.branch, weights.init_params(config, seed),
+        [jax.device_put(b) for b in host_batches], w, hp, config)
     log(f"train: reference {time.perf_counter() - t_ref:.2f} s; losses "
         f"program {losses!r} reference {readings['loss']!r}")
     ref_grad = np.asarray(readings["grad_norms"])

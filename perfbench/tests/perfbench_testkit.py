@@ -15,11 +15,11 @@ from perfbench import harness  # noqa: E402
 
 
 def tiny_config(name="hydragnn-gfm", **kw):
+    """Configuration ``name`` at its reference's small sizes, with ``kw``
+    over them."""
     cfg = harness.load_json(os.path.join(harness.BENCH_DIR, "configs",
                                          name + ".json"))
-    cfg.update(gnn_hidden=16, gnn_layers=2, head_hidden=8, head_layers=1)
-    cfg.update(kw)
-    return cfg
+    return dict(harness.reference(cfg).tiny(cfg), **kw)
 
 
 def tiny_sources(total=300):
